@@ -36,6 +36,9 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     quick = "--quick" in sys.argv
     smoke = "--smoke" in sys.argv
     from . import (analysis_bench, async_bench, kernel_bench, obs_bench,

@@ -3,7 +3,7 @@
 Every registered engine is instantiated on a canonical tiny problem and
 its fused outer-iteration program(s) are traced with
 :func:`jax.make_jaxpr` — tracing only, nothing runs.  The checker then
-walks the closed jaxpr (recursing into ``pjit`` / ``shard_map`` /
+walks the closed jaxpr (recursing into ``jit`` / ``shard_map`` /
 ``while`` / ``scan`` sub-jaxprs, tracking loop depth) and statically
 counts:
 
@@ -29,6 +29,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 from .findings import Finding
 
@@ -47,10 +48,10 @@ LOOP_PRIMS = ("while", "scan")
 
 def _sub_jaxprs(value: Any):
     """Yield jaxprs hiding in one eqn param value (jaxpr, closed jaxpr,
-    or (nested) sequences thereof — pjit, shard_map, custom_*, cond)."""
-    if isinstance(value, jax.core.ClosedJaxpr):
+    or (nested) sequences thereof — jit, shard_map, custom_*, cond)."""
+    if isinstance(value, jex_core.ClosedJaxpr):
         yield value.jaxpr
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, jex_core.Jaxpr):
         yield value
     elif isinstance(value, (list, tuple)):
         for v in value:
@@ -103,7 +104,7 @@ def count_program(closed) -> ProgramFacts:
         if dtype is not None and dtype == jnp.float64:
             facts.f64_avals += 1
 
-    def walk(jaxpr: jax.core.Jaxpr, depth: int) -> None:
+    def walk(jaxpr: jex_core.Jaxpr, depth: int) -> None:
         for eqn in jaxpr.eqns:
             visit(eqn, depth)
             d = depth + 1 if eqn.primitive.name in LOOP_PRIMS else depth
@@ -111,7 +112,7 @@ def count_program(closed) -> ProgramFacts:
                 for sub in _sub_jaxprs(v):
                     walk(sub, d)
 
-    walk(closed.jaxpr if isinstance(closed, jax.core.ClosedJaxpr)
+    walk(closed.jaxpr if isinstance(closed, jex_core.ClosedJaxpr)
          else closed, 0)
     return facts
 
@@ -128,7 +129,7 @@ class ProgramTrace:
     name: str                     # "outer" | "continue"
     fn: Callable
     args: Tuple
-    jaxpr: jax.core.ClosedJaxpr
+    jaxpr: jex_core.ClosedJaxpr
     out_shape: Any
     facts: ProgramFacts
 
@@ -322,7 +323,7 @@ def _check_async_pipeline(et: EngineTrace,
     """Rule J009: async engines really are a two-program pipeline.
 
     For engines declaring ``EngineCapabilities.async_oracle``, the traced
-    outer iteration must contain exactly two top-level ``pjit`` dispatches
+    outer iteration must contain exactly two top-level ``jit`` dispatches
     — one whose name carries ``async_oracle`` (the exact max-oracle over
     the next iteration's blocks) and one carrying ``async_cache`` (the
     eviction + fold-in + approximate batch).  Statically proven on the
@@ -336,7 +337,7 @@ def _check_async_pipeline(et: EngineTrace,
         host round-trip) inside it would serialize the pipeline;
       * no read-after-write hazard: the cache program must not consume
         any output of the concurrently-dispatched oracle program (and
-        vice versa) — a data dependence between the two pjit eqns would
+        vice versa) — a data dependence between the two jit eqns would
         force XLA to run them back to back, silently voiding the
         overlap the ``oracle_overlap`` column reports.
     """
@@ -346,7 +347,7 @@ def _check_async_pipeline(et: EngineTrace,
     out: List[Finding] = []
     oracle_eqns, cache_eqns = [], []
     for eqn in prog.jaxpr.jaxpr.eqns:
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":
             continue
         nm = str(eqn.params.get("name", ""))
         if "async_oracle" in nm:
@@ -357,7 +358,7 @@ def _check_async_pipeline(et: EngineTrace,
         out.append(Finding(
             "J009", where,
             f"expected exactly one async_oracle and one async_cache "
-            f"pjit dispatch at the top level, found "
+            f"jit dispatch at the top level, found "
             f"{len(oracle_eqns)} oracle / {len(cache_eqns)} cache"))
         return out
     o_eqn, c_eqn = oracle_eqns[0], cache_eqns[0]
@@ -371,7 +372,7 @@ def _check_async_pipeline(et: EngineTrace,
                 f"(detail: {f.detail}); it must be communication-free "
                 "to overlap the cache program"))
     o_out = set(o_eqn.outvars)
-    c_in = {v for v in c_eqn.invars if isinstance(v, jax.core.Var)}
+    c_in = {v for v in c_eqn.invars if isinstance(v, jex_core.Var)}
     if o_out & c_in:
         out.append(Finding(
             "J009", where,
@@ -379,7 +380,7 @@ def _check_async_pipeline(et: EngineTrace,
             f"{len(o_out & c_in)} output(s) of the concurrent "
             "async_oracle program — the two dispatches would serialize"))
     c_out = set(c_eqn.outvars)
-    o_in = {v for v in o_eqn.invars if isinstance(v, jax.core.Var)}
+    o_in = {v for v in o_eqn.invars if isinstance(v, jex_core.Var)}
     if c_out & o_in:
         out.append(Finding(
             "J009", where,

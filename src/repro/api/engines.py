@@ -137,20 +137,22 @@ class FusedEngine(_EngineBase):
 
     def outer_iteration(self, mp, perm, perms, clock, *, ttl: int,
                         key=None):
-        """Dispatch one fused outer iteration (no blocking)."""
+        """Dispatch one fused outer iteration (no blocking).  ``mp`` is
+        donated: its buffers become the returned state's."""
         self.ledger.dispatched()
         return mpbcfw.jit_outer_iteration(
             self.problem, mp, perm, perms, clock,
             lam=self.lam, ttl=ttl, steps=self.gram_steps,
-            policies=self.policies, key=key)
+            policies=self.policies, key=key, donate=True)
 
     def continue_passes(self, mp, perms, clock):
         """Overflow batch of approximate passes (rare: only when an
-        iteration runs more than ``approx_batch`` passes)."""
+        iteration runs more than ``approx_batch`` passes).  ``mp`` is
+        donated, as in :meth:`outer_iteration`."""
         self.ledger.dispatched()
         return mpbcfw.jit_multi_approx_pass(
             self.problem, mp, perms, clock, lam=self.lam,
-            steps=self.gram_steps, policies=self.policies)
+            steps=self.gram_steps, policies=self.policies, donate=True)
 
     def read_stats(self, stats):
         return self.ledger.sync(stats)

@@ -396,6 +396,9 @@ class Solver:
                 if self.caps.needs_key else {})
             mp, clock_dev, stats = engine.outer_iteration(
                 mp, perm, perms, clock_dev, ttl=cfg.ttl, **key_kw)
+            # Engines may donate the state they are given (FusedEngine
+            # does): rebind at once, so nothing reads a donated buffer.
+            self._state = mp
             st = engine.read_stats(stats)  # the iteration's single sync
             t_sync = clock.now()
             # Device-accumulated obs counters arrive on the same sync.
@@ -423,6 +426,7 @@ class Solver:
                 perms = _draw_perms(rng, n, batch)
                 mp, clock_dev, stats = engine.continue_passes(mp, perms,
                                                               clock_dev)
+                self._state = mp
                 st = engine.read_stats(stats)
                 t_prev, t_sync = t_sync, clock.now()
                 k = int(st.passes_run)
@@ -538,7 +542,6 @@ class Solver:
             with clock.exclude():
                 primal, dual, primal_avg = engine.evaluate(mp)
             f_end = dual
-            self._state = mp
             yield TraceRow(
                 it, int(mp.inner.n_exact), int(mp.inner.n_approx),
                 clock.now(), primal, dual, primal - dual, primal_avg,

@@ -17,8 +17,10 @@ from .ssvm import dual_value
 
 
 def init_averaging(d: int) -> AveragingState:
-    z = jnp.zeros((d + 1,), jnp.float32)
-    return AveragingState(bar_exact=z, bar_approx=z,
+    # Two buffers, not one shared zero: the fused engines donate the
+    # state, and a buffer cannot be donated twice.
+    return AveragingState(bar_exact=jnp.zeros((d + 1,), jnp.float32),
+                          bar_approx=jnp.zeros((d + 1,), jnp.float32),
                           k_exact=jnp.zeros((), jnp.int32),
                           k_approx=jnp.zeros((), jnp.int32))
 

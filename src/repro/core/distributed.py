@@ -166,11 +166,14 @@ def jit_fold_planes(mp: MPState, block_ids, planes, fb_planes, fb_slots,
                        lam, scatter=scatter)
 
 
-def tau_chunk(oracle, data, mp: MPState, ids: jnp.ndarray, ok: jnp.ndarray,
-              lam: float, oracle_stage=None,
+def tau_chunk(oracle, data, mp: MPState, ids: jnp.ndarray,
+              ok: Optional[jnp.ndarray], lam: float, oracle_stage=None,
               scatter: str = "per-elem") -> MPState:
     """One tau-nice chunk: parallel oracles at the chunk's stale ``w``,
     batched cached fallback at the same ``w``, sequential fold-in.
+
+    ``ok=None`` means every oracle result arrived: no fallback is scored,
+    and the fold is the one an all-true mask gives, bit for bit.
 
     This is the shared chunk body: the host reference jits it once per
     chunk shape and loops on the host; the :mod:`repro.shard` engine scans
@@ -185,7 +188,11 @@ def tau_chunk(oracle, data, mp: MPState, ids: jnp.ndarray, ok: jnp.ndarray,
         planes = jax.vmap(lambda ex: oracle(w, ex))(batch)
     else:
         planes = oracle_stage(data, w, ids)
-    fbp, fbs, _ = fallback_planes(mp.cache, ids, w)
+    if ok is None:
+        ok = jnp.ones(ids.shape, bool)
+        fbp, fbs = planes, jnp.zeros(ids.shape, jnp.int32)
+    else:
+        fbp, fbs, _ = fallback_planes(mp.cache, ids, w)
     return fold_planes(mp, ids, planes, fbp, fbs, ok, lam, scatter=scatter)
 
 
@@ -210,7 +217,7 @@ def host_tau_nice_pass(problem: SSVMProblem, mp: MPState, perm: jnp.ndarray,
     assert n % tau == 0, "perm length must be divisible by tau"
     for c in range(n // tau):
         ids = perm[c * tau:(c + 1) * tau]
-        ok = jnp.ones((tau,), bool) if done is None else done[c]
+        ok = None if done is None else done[c]
         mp = _jit_tau_chunk(problem.oracle, problem.data, mp, ids, ok,
                             lam=lam)
     return mp

@@ -297,21 +297,35 @@ def multi_approx_pass(mp: MPState, perms: jnp.ndarray, clock: SlopeClock,
     return mp, clock._replace(t=t), stats._replace(metrics=metrics)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("lam", "steps", "run_all", "policies"))
-def _jit_multi_approx_pass(mp, perms, clock, *, lam, steps, run_all,
-                           policies=None):
+def _multi_approx_program(mp, perms, clock, *, lam, steps, run_all,
+                          policies=None):
     return multi_approx_pass(mp, perms, clock, lam=lam, steps=steps,
                              run_all=run_all, policies=policies)
+
+
+_MULTI_STATIC = ("lam", "steps", "run_all", "policies")
+_jit_multi_approx_pass = jax.jit(_multi_approx_program,
+                                 static_argnames=_MULTI_STATIC)
+_jit_multi_approx_pass_donating = jax.jit(
+    _multi_approx_program, static_argnames=_MULTI_STATIC,
+    donate_argnames=("mp",))
 
 
 def jit_multi_approx_pass(problem: Optional[SSVMProblem], mp: MPState,
                           perms: jnp.ndarray, clock: SlopeClock, *,
                           lam: float, steps: int = 10,
-                          run_all: bool = False, policies=None):
+                          run_all: bool = False, policies=None,
+                          donate: bool = False):
+    """Jitted :func:`multi_approx_pass`.
+
+    ``donate=True`` hands ``mp``'s buffers to the program, which then
+    updates the plane cache in place: the caller must not read ``mp``
+    afterwards.
+    """
     del problem  # approximate passes never touch the data
-    return _jit_multi_approx_pass(mp, perms, clock, lam=lam, steps=steps,
-                                  run_all=run_all, policies=policies)
+    fn = _jit_multi_approx_pass_donating if donate else _jit_multi_approx_pass
+    return fn(mp, perms, clock, lam=lam, steps=steps, run_all=run_all,
+              policies=policies)
 
 
 def outer_iteration(problem: SSVMProblem, mp: MPState, perm: jnp.ndarray,
@@ -373,11 +387,8 @@ def outer_iteration(problem: SSVMProblem, mp: MPState, perm: jnp.ndarray,
     return mp, clock, stats._replace(metrics=metrics)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1),
-                   static_argnames=("lam", "ttl", "steps", "run_all",
-                                    "policies"))
-def _jit_outer_iteration(oracle, n, data, mp, perm, perms, clock, key,
-                         *, lam, ttl, steps, run_all, policies=None):
+def _outer_program(oracle, n, data, mp, perm, perms, clock, key,
+                   *, lam, ttl, steps, run_all, policies=None):
     prob = SSVMProblem(n=n, d=mp.inner.phi.shape[0] - 1, data=data,
                        oracle=oracle)
     return outer_iteration(prob, mp, perm, perms, clock, lam=lam,
@@ -385,20 +396,35 @@ def _jit_outer_iteration(oracle, n, data, mp, perm, perms, clock, key,
                            policies=policies, key=key)
 
 
+_OUTER_STATIC = dict(static_argnums=(0, 1),
+                     static_argnames=("lam", "ttl", "steps", "run_all",
+                                      "policies"))
+_jit_outer_iteration = jax.jit(_outer_program, **_OUTER_STATIC)
+_jit_outer_iteration_donating = jax.jit(_outer_program,
+                                        donate_argnames=("mp",),
+                                        **_OUTER_STATIC)
+
+
 def jit_outer_iteration(problem: SSVMProblem, mp: MPState,
                         perm: jnp.ndarray, perms: jnp.ndarray,
                         clock: SlopeClock, *, lam: float, ttl: int,
                         steps: int = 10, run_all: bool = False,
-                        policies=None, key: Optional[jnp.ndarray] = None):
+                        policies=None, key: Optional[jnp.ndarray] = None,
+                        donate: bool = False):
     """Jitted :func:`outer_iteration` (cached per oracle/shape/flags).
 
     ``policies`` is jit-static (frozen bundle); ``key`` is a traced PRNG
     key (or ``None`` — an empty pytree — when no policy needs one).
+    ``donate=True`` hands ``mp``'s buffers to the program so the new
+    state reuses them: without it the program holds the input and the
+    output plane cache at once, which at the paper's OCR size (n=6877,
+    d=4004, cap=64: 7.05 GB of planes) takes nearly all of a 16 GB chip.
+    The caller must not read ``mp`` afterwards.
     """
-    return _jit_outer_iteration(problem.oracle, problem.n, problem.data,
-                                mp, perm, perms, clock, key, lam=lam,
-                                ttl=ttl, steps=steps, run_all=run_all,
-                                policies=policies)
+    fn = _jit_outer_iteration_donating if donate else _jit_outer_iteration
+    return fn(problem.oracle, problem.n, problem.data, mp, perm, perms,
+              clock, key, lam=lam, ttl=ttl, steps=steps, run_all=run_all,
+              policies=policies)
 
 
 def init_mp_state(problem: SSVMProblem,

@@ -1,9 +1,10 @@
-"""Jit'd dispatch wrappers for the Pallas kernels.
+"""Dispatch wrappers for the Pallas kernels.
 
-On TPU the compiled kernels run; everywhere else (this CPU container, CI)
-the wrappers fall back to interpret mode (``interpret=True`` executes the
-kernel body faithfully) or, for bulk use inside models, to the pure-jnp
-reference — selected via :func:`use_pallas`.
+On a TPU backend the wrappers call the compiled Pallas kernels; on any
+other backend they call the pure-jnp references in :mod:`repro.kernels.ref`
+instead — :func:`use_pallas` decides from ``jax.default_backend()`` alone.
+Interpret mode is never chosen here: the kernel tests reach it by calling
+a kernel module directly with ``interpret=True``.
 """
 from __future__ import annotations
 
@@ -31,7 +32,7 @@ def on_tpu() -> bool:
 
 
 def use_pallas() -> bool:
-    """Compiled Pallas only on real TPU; callers may force via config."""
+    """Compiled Pallas kernels on a TPU backend, the references elsewhere."""
     return on_tpu()
 
 
@@ -102,12 +103,6 @@ def gram(planes, **kw):
     if use_pallas():
         return _gram.gram(planes, **kw)
     return ref.gram_ref(planes)
-
-
-def viterbi_step(m, trans, **kw):
-    if use_pallas():
-        return _vit.viterbi_step(m, trans, **kw)
-    return ref.viterbi_step_ref(m, trans)
 
 
 def flash_attention(q, k, v, sm_scale=None, **kw):

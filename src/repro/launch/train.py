@@ -128,6 +128,9 @@ def train_ssvm(scenario: str, iters: int, algo: str = "mpbcfw") -> dict:
 
 
 def main():
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--trainer", choices=["lm", "ssvm"], default="lm")
     ap.add_argument("--arch", default="qwen2-0.5b")

@@ -20,7 +20,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import cache as plane_cache
@@ -332,11 +331,11 @@ class ShardEngine:
             metrics=ObsMetrics(ttl_evicted=P(), lru_evicted=P(),
                                occupancy=P(), nonempty_blocks=P(),
                                gap_total=P() if track_gap else None))
-        return shard_map(
+        return jax.shard_map(
             local_prog, mesh=mesh,
             in_specs=(mp_specs, P(None, None), clock_specs, P(axis, None)),
             out_specs=(mp_specs, clock_specs, stats_specs),
-            check_rep=False)
+            check_vma=False)
 
     def _multi_stage(self, run_all: bool):
         """The shard_map'd multi-pass callable (traceable, unjitted) —
@@ -388,12 +387,16 @@ class ShardEngine:
             batch = jax.tree_util.tree_map(lambda a: a[ids_loc], data)
             return jax.vmap(lambda ex: oracle(w, ex))(batch)
 
-        oracle_stage = shard_map(
+        oracle_stage = jax.shard_map(
             local_oracles, mesh=mesh,
             in_specs=(data_specs, P(None), P(axis)),
-            out_specs=P(axis, None), check_rep=False)
+            out_specs=P(axis, None), check_vma=False)
 
         def epoch(data, mp: MPState, chunk_ids, done):
+            # done=None: no stragglers, so no fallback is scored.  That
+            # also keeps the Pallas score-and-select kernel out of this
+            # GSPMD-partitioned scan, where a multi-chip mesh cannot
+            # partition it.
             def chunk(mp_c, inp):
                 ids, ok = inp
                 return distributed.tau_chunk(
@@ -421,9 +424,7 @@ class ShardEngine:
             raise ValueError(
                 f"tau={tau} not divisible by {self.n_shards} shards")
         chunk_ids = perm.reshape(-1, tau)
-        if done is None:
-            done = jnp.ones(chunk_ids.shape, bool)
-        else:
+        if done is not None:
             done = done.reshape(chunk_ids.shape)
         return chunk_ids, done
 
@@ -568,10 +569,10 @@ class ShardEngine:
             batch = jax.tree_util.tree_map(lambda a: a[ids_loc], data)
             return jax.vmap(lambda ex: oracle(w, ex))(batch)
 
-        oracle_stage = shard_map(
+        oracle_stage = jax.shard_map(
             local_oracles, mesh=mesh,
             in_specs=(data_specs, P(None), P(axis)),
-            out_specs=P(axis, None), check_rep=False)
+            out_specs=P(axis, None), check_vma=False)
 
         def shard_async_oracle(data, phi, perm):
             w = weights_of(phi, lam)

@@ -27,7 +27,6 @@ from repro.analysis.lint import parse_waivers, run_lint_layer
 def test_count_program_psum_depths():
     """A psum outside a loop counts as setup; inside the while loop of a
     fori_loop as per-pass — under shard_map, like the real engines."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_data_mesh
@@ -42,7 +41,7 @@ def test_count_program_psum_depths():
 
         return jax.lax.fori_loop(0, 3, body, setup)
 
-    sharded = shard_map(f, mesh=mesh, in_specs=P("i"), out_specs=P())
+    sharded = jax.shard_map(f, mesh=mesh, in_specs=P("i"), out_specs=P())
     jaxpr = jax.make_jaxpr(sharded)(jnp.ones(4))
     facts = count_program(jaxpr)
     assert facts.setup_collectives == 1, facts.detail

@@ -377,6 +377,32 @@ def test_device_slope_rule_matches_host_tracker(multiclass_problem):
     assert decisions_checked >= 8   # the regime actually exercised the rule
 
 
+def test_fused_engine_donates_state(multiclass_problem):
+    """FusedEngine hands the state to its fused programs (the paper-size
+    OCR cache fits one chip only so); a direct jit_outer_iteration call
+    keeps the caller's state, and both compute the same iteration."""
+    from repro.api.engines import FusedEngine
+
+    prob = multiclass_problem
+    lam = 1.0 / prob.n
+    rng = np.random.RandomState(0)
+    perm = jnp.asarray(rng.permutation(prob.n))
+    perms = jnp.asarray(np.stack([rng.permutation(prob.n)
+                                  for _ in range(2)]))
+    clock = mpbcfw.make_slope_clock(0.0, 0.0, 1.0, 1e-3)
+    engine = FusedEngine(prob, lam)
+    mp = engine.init_state(8)
+    kept, _, _ = mpbcfw.jit_outer_iteration(prob, mp, perm, perms, clock,
+                                            lam=lam, ttl=10)
+    assert not any(a.is_deleted() for a in jax.tree_util.tree_leaves(mp))
+    mp2, clock2, _ = engine.outer_iteration(mp, perm, perms, clock, ttl=10)
+    assert all(a.is_deleted() for a in jax.tree_util.tree_leaves(mp))
+    assert np.array_equal(np.asarray(kept.inner.phi),
+                          np.asarray(mp2.inner.phi))
+    engine.continue_passes(mp2, perms, clock2)
+    assert mp2.cache.planes.is_deleted()
+
+
 # ---------------------------------------------------------------------------
 # Third-party extension points (no edits to repro.core)
 

@@ -300,14 +300,14 @@ def test_j009_async_engines_clean():
         outer = next(p for p in et.programs if p.name == "outer")
         names = [str(e.params.get("name", ""))
                  for e in outer.jaxpr.jaxpr.eqns if e.primitive.name ==
-                 "pjit"]
+                 "jit"]
         assert any("async_oracle" in s for s in names)
         assert any("async_cache" in s for s in names)
 
 
 def test_j009_flags_fused_engine_masquerading_as_async():
     """A one-program engine that *declares* async_oracle has no
-    async_oracle/async_cache pjit pair — J009 must fire."""
+    async_oracle/async_cache jit pair — J009 must fire."""
     from repro.analysis.contracts import (EngineTrace, check_trace,
                                           trace_engine)
 
