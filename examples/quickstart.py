@@ -91,12 +91,11 @@ def main():
                                        cap=32, gap_tol=1e-3,
                                        cost_model=cm()))
     for row in solver.iterate():            # rows stream as iterations run
-        # cache_hit_rate / planes_evicted / oracle_share are measured
-        # on-device and drained through the same single host sync as the
-        # rest of the row (see the README's Observability section).
+        # cache_hit_rate / planes_evicted are measured on-device and
+        # drained through the same single host sync as the rest of the
+        # row (see the README's Observability section).
         print(f"  iter {row.iteration:2d}  gap {row.gap:.6f}  "
               f"hit {row.cache_hit_rate:.2f}  evicted {row.planes_evicted}  "
-              f"oracle share {row.oracle_share:.2f}  "
               f"[{row.dispatches} dispatch / {row.host_syncs} sync]")
     print(f"stopped after {solver.iteration} of 50 iterations "
           f"(gap_tol=1e-3, final gap {solver.trace[-1].gap:.2e})")
@@ -180,8 +179,8 @@ def main():
 
     # -- record a run: repro.obs (spans + metrics, zero extra syncs) -------
     # The recorder is a Solver callback: it streams JSONL (meta, rows,
-    # spans, events, summary), exportable to Perfetto, and summarized by
-    # `python -m repro.obs run.jsonl`.
+    # spans, events, summary), summarized by `python -m repro.obs
+    # run.jsonl`.
     import tempfile
 
     from repro.obs import RunRecorder, summarize_run
@@ -193,7 +192,7 @@ def main():
                    recorder=rec).run()
         s = summarize_run(tmp.name)
         print(f"recorded run: {s['iterations']} iterations  "
-              f"oracle share {s['oracle_share_mean']:.2f}  "
+              f"approx passes/iter {s['approx_passes_mean']:.1f}  "
               f"host_syncs/iter <= "
               f"{s['contract']['host_syncs_per_iter_max']}")
 

@@ -79,10 +79,6 @@ class TraceRow:
     #                               plane (an approx visit to such a block
     #                               is a cache hit; 0 planes falls back)
     planes_evicted: int = 0       # TTL + LRU evictions this iteration
-    oracle_share: float = 1.0     # modeled share of iteration time spent
-    #                               in the exact max-oracle pass (the
-    #                               paper's costly-oracle regime has this
-    #                               near 1)
     oracle_overlap: float = 0.0   # async engines: fraction of the exact
     #                               oracle's modeled time hidden behind the
     #                               concurrently-dispatched cache program
@@ -93,6 +89,14 @@ class TraceRow:
     #                               estimates after the exact pass
     gap_sampled: int = 0          # blocks the sampling policy scheduled
     #                               for the exact pass this iteration
+    # Host columns, measured in wall-clock mode (a CostModel run keeps
+    # its rows deterministic and reports 0):
+    eval_s: float = 0.0           # wall seconds of this iteration's
+    #                               evaluation (primal, dual, gap), which
+    #                               TraceRow.time leaves out
+    compiles: int = 0             # executables JAX made during the
+    #                               iteration, compiled or loaded from the
+    #                               persistent cache (repro.obs.spans)
 
 
 @dataclass

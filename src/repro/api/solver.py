@@ -28,8 +28,14 @@ per-pass telemetry is replayed into the host-side
 Evaluation (:func:`evaluate_objectives`: primal/dual/gap, n — 2n with
 averaging — extra oracle calls per iteration) is telemetry, **not** part
 of the control loop: its wall time is measured and subtracted from every
-clock reading (``_Clock.exclude``), and its device fetches are not
-charged to the ledger.
+clock reading (``_Clock.exclude``), reported in ``TraceRow.eval_s``, and
+its device fetches are not charged to the ledger.
+
+Tracing (:mod:`repro.obs.spans`) is always on and observes only: each
+outer iteration is a profiler step ``repro:iteration`` holding
+``repro:dispatch`` (the engine's program dispatches), ``repro:sync``
+(``read_stats``) and ``repro:evaluate`` spans, and ``TraceRow.compiles``
+counts the executables JAX made during the iteration.
 
 Stopping is pluggable (:mod:`repro.api.stopping`): ``max_iters``, an
 optional wall/virtual-time budget, and an optional duality-gap tolerance
@@ -41,8 +47,10 @@ the uninterrupted one.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from contextlib import contextmanager, nullcontext
+from types import SimpleNamespace
 from typing import Callable, Iterable, Iterator, List, Optional, TYPE_CHECKING
 
 import jax
@@ -58,6 +66,7 @@ if TYPE_CHECKING:  # annotation only
     from ..obs.recorder import RunRecorder
 from ..core.ssvm import batched_oracle, dual_value, weights_of
 from ..core.averaging import extract as extract_average
+from ..obs import spans
 from ..core.types import SSVMProblem
 from .config import RunConfig, RunResult, TraceRow
 from .engine import Engine, engine_entry, validate_config
@@ -95,12 +104,15 @@ class _Clock:
 
     @contextmanager
     def exclude(self):
-        """Context whose wall time never reaches trace rows."""
+        """Context whose wall time never reaches trace rows; on exit the
+        object it yields holds that time in ``seconds``."""
+        out = SimpleNamespace(seconds=0.0)
         t0 = time.perf_counter()
         try:
-            yield
+            yield out
         finally:
-            self._excluded += time.perf_counter() - t0
+            out.seconds = time.perf_counter() - t0
+            self._excluded += out.seconds
 
     def exact(self, n_calls: int) -> float:
         if self.cm is not None:
@@ -251,6 +263,7 @@ class Solver:
         self._est_plane = cm.plane_cost if cm is not None else 1e-3
         self._wall_x: List[float] = []  # plane-steps per iter (regressor)
         self._wall_y: List[float] = []  # measured iteration seconds
+        spans.compile_count()  # the listener counts from here on
 
     # -- state / results ----------------------------------------------------
 
@@ -291,12 +304,16 @@ class Solver:
                  else self._iterate_simple())
         ledger = getattr(self.engine, "ledger", None)
         while not self._should_stop():
-            ann = (self.recorder.step_annotation(self._it)
-                   if self.recorder is not None else nullcontext())
             coll0 = getattr(ledger, "collectives", 0)
             bytes0 = getattr(ledger, "collective_bytes", 0)
-            with ann:
+            n_exact0 = (self._last_row.n_exact
+                        if self._last_row is not None else 0)
+            with spans.step(spans.ITERATION, self._it) as step:
                 row = next(inner)
+                # The iteration's counts ride its profiler step, so a
+                # trace reader can put device time per call and pass.
+                step.set_metadata(exact_calls=row.n_exact - n_exact0,
+                                  approx_passes=row.approx_passes)
             self.trace.append(row)
             self._last_row = row
             self._it += 1
@@ -316,6 +333,20 @@ class Solver:
                     self.save(self.checkpoint)
             yield row
 
+    def _evaluate(self, state, compiles0: int):
+        """``engine.evaluate`` outside the clock, under its span.  Returns
+        the objectives and the row's host columns: ``eval_s``, the
+        seconds the clock left out for the call, and ``compiles``, the
+        executables made since ``compiles0``.  Under a CostModel the row
+        holds only what the virtual clock makes deterministic, so both
+        stay 0."""
+        with self._clock.exclude() as excluded, spans.span(spans.EVALUATE):
+            objectives = self.engine.evaluate(state)
+        if self._clock.cm is not None:
+            return objectives, {}
+        return objectives, dict(eval_s=excluded.seconds,
+                                compiles=spans.compile_count() - compiles0)
+
     def _iterate_simple(self) -> Iterator[TraceRow]:
         """One fused program per outer iteration, no approximate phase
         (fw / ssg / bcfw and any registered non-multipass engine)."""
@@ -323,19 +354,23 @@ class Solver:
         n = self.problem.n
         while True:
             it = self._it
+            compiles0 = spans.compile_count()
             led0 = engine.ledger.counts()
             perm = (jnp.asarray(self._rng.permutation(n))
                     if self.caps.needs_perm else None)
-            self._state, _, stats = engine.outer_iteration(
-                self._state, perm, None, None, ttl=cfg.ttl)
-            st = engine.read_stats(stats)  # the iteration's single sync
+            with spans.span(spans.DISPATCH):
+                self._state, _, stats = engine.outer_iteration(
+                    self._state, perm, None, None, ttl=cfg.ttl)
+            with spans.span(spans.SYNC):
+                st = engine.read_stats(stats)  # the iteration's single sync
             t = clock.exact(n)
-            with clock.exclude():
-                primal, dual, primal_avg = engine.evaluate(self._state)
+            (primal, dual, primal_avg), host = self._evaluate(self._state,
+                                                              compiles0)
             led1 = engine.ledger.counts()
             yield TraceRow(it, int(st.n_exact), int(st.n_approx), t,
                            primal, dual, primal - dual, primal_avg,
-                           0.0, 0, led1[0] - led0[0], led1[2] - led0[2])
+                           0.0, 0, led1[0] - led0[0], led1[2] - led0[2],
+                           **host)
 
     def _iterate_multipass(self) -> Iterator[TraceRow]:
         """The MP-BCFW control loop, generic over the execution engine.
@@ -356,6 +391,7 @@ class Solver:
         f_end = float(dual_value(self._state.inner.phi, lam))
         while True:
             it = self._it
+            compiles0 = spans.compile_count()
             mp = self._state
             led0 = engine.ledger.counts()
             # Async engines accumulate modeled oracle-overlap time on the
@@ -394,13 +430,14 @@ class Solver:
             key_kw = ({"key": jax.random.PRNGKey(
                 int(rng.randint(0, 2 ** 31 - 1)))}
                 if self.caps.needs_key else {})
-            mp, clock_dev, stats = engine.outer_iteration(
-                mp, perm, perms, clock_dev, ttl=cfg.ttl, **key_kw)
+            with spans.span(spans.DISPATCH):
+                mp, clock_dev, stats = engine.outer_iteration(
+                    mp, perm, perms, clock_dev, ttl=cfg.ttl, **key_kw)
             # Engines may donate the state they are given (FusedEngine
             # does): rebind at once, so nothing reads a donated buffer.
             self._state = mp
-            st = engine.read_stats(stats)  # the iteration's single sync
-            t_sync = clock.now()
+            with spans.span(spans.SYNC):
+                st = engine.read_stats(stats)  # the iteration's single sync
             # Device-accumulated obs counters arrive on the same sync.
             # Capture them from the *outer* program's stats: overflow
             # continuations never insert/evict, so their metrics carry
@@ -412,30 +449,19 @@ class Solver:
             k = int(st.passes_run)
             duals_all = [float(x) for x in st.duals[:k]]
             planes_all = [int(x) for x in st.planes[:k]]
-            # Measured program-boundary segments: every read_stats is a
-            # host sync the loop already pays for, so timestamping each
-            # boundary is free.  Segment 0 spans the fused exact(+first
-            # approx batch) program; later segments are *approx-only*
-            # overflow continuations — the recorder calibrates the real
-            # exact-vs-plane cost split from these instead of pro-rata
-            # attribution (wall mode).
-            segs = [(sum(max(p, 1) for p in planes_all), t_sync - t0)]
             while bool(st.more) and len(duals_all) < cfg.max_approx_passes:
                 batch = min(cfg.approx_batch,
                             cfg.max_approx_passes - len(duals_all))
                 perms = _draw_perms(rng, n, batch)
-                mp, clock_dev, stats = engine.continue_passes(mp, perms,
-                                                              clock_dev)
+                with spans.span(spans.DISPATCH):
+                    mp, clock_dev, stats = engine.continue_passes(
+                        mp, perms, clock_dev)
                 self._state = mp
-                st = engine.read_stats(stats)
-                t_prev, t_sync = t_sync, clock.now()
+                with spans.span(spans.SYNC):
+                    st = engine.read_stats(stats)
                 k = int(st.passes_run)
-                b_duals = [float(x) for x in st.duals[:k]]
-                b_planes = [int(x) for x in st.planes[:k]]
-                duals_all += b_duals
-                planes_all += b_planes
-                segs.append((sum(max(p, 1) for p in b_planes),
-                             t_sync - t_prev))
+                duals_all += [float(x) for x in st.duals[:k]]
+                planes_all += [int(x) for x in st.planes[:k]]
             led1 = engine.ledger.counts()
             ovl_total = (getattr(engine.ledger, "oracle_time_total", 0.0)
                          - ovl0[0])
@@ -478,36 +504,23 @@ class Solver:
                     ts.append(t_cursor)
                 tracker.record(ts[0], f_exact)
                 tracker.record_batch(ts[1:], duals_all)
-                # Calibrate the device rule's cost constants.  Pro-rata
-                # attribution alone preserves the est_exact/est_plane
-                # *ratio*, so it drifts when pass counts barely vary.
-                # With a recorder the measured program-boundary segments
-                # above calibrate the split directly (overflow segments
-                # are approx-only, identifying the per-plane cost without
-                # any regression); the constants persist through the
-                # checkpoint manifest's ``extra["calibration"]`` either
-                # way.  Without one, regress elapsed ~ a + b*plane_steps
-                # across iterations as before.
+                # Calibrate the device rule's cost constants: regress
+                # elapsed ~ a + b*plane_steps across iterations, falling
+                # back to the pro-rata split until the window identifies
+                # both terms.  A recorder, attached or not, changes
+                # nothing here.  The constants persist through the
+                # checkpoint manifest's ``extra["calibration"]``.
                 self._wall_x.append(float(sum(max(p, 1)
                                               for p in planes_all)))
                 self._wall_y.append(float(elapsed))
-                if self.recorder is not None:
-                    fit = self.recorder.observe_phases(segs)
-                    if fit is not None:
-                        self._est_exact, self._est_plane = fit
-                    # No fit yet: keep the current constants rather than
-                    # re-deriving them pro-rata — exactly the drift the
-                    # recorder path removes.
+                fit = _fit_pass_costs(self._wall_x, self._wall_y)
+                if fit is not None:
+                    self._est_exact, self._est_plane = fit
                 else:
-                    fit = _fit_pass_costs(self._wall_x, self._wall_y)
-                    if fit is not None:
-                        self._est_exact, self._est_plane = fit
-                    else:
-                        self._est_exact = max(durs[0], 1e-9)
-                        if planes_all:
-                            tot = sum(max(p, 1) for p in planes_all)
-                            self._est_plane = max(sum(durs[1:]) / tot,
-                                                  1e-12)
+                    self._est_exact = max(durs[0], 1e-9)
+                    if planes_all:
+                        tot = sum(max(p, 1) for p in planes_all)
+                        self._est_plane = max(sum(durs[1:]) / tot, 1e-12)
 
             n_approx_passes = len(duals_all)
             # One statistic in both branches (Fig. 5): the mean working-
@@ -517,15 +530,6 @@ class Solver:
             # iteration sees the post-exact-pass sets and the per-pass
             # mean is exactly ws_total/n.
             ws_mean = ws_total / n
-            # Obs columns.  oracle_share uses the same modeled weights as
-            # the wall-time attribution above, so it is identical across
-            # engines given identical pass schedules (bitwise: floats
-            # from the same host arithmetic) and defined in both clock
-            # modes.
-            w_exact = self._est_exact
-            w_total = w_exact + sum(self._est_plane * max(p, 1)
-                                    for p in planes_all)
-            oracle_share = w_exact / w_total if w_total > 0 else 1.0
             if met is not None:
                 hit_rate = int(met.nonempty_blocks) / n
                 evicted = int(met.ttl_evicted) + int(met.lru_evicted)
@@ -539,8 +543,8 @@ class Solver:
                 gs = getattr(met, "gap_sampled", None)
                 gap_kw = dict(gap_total=float(gt),
                               gap_sampled=int(gs) if gs is not None else 0)
-            with clock.exclude():
-                primal, dual, primal_avg = engine.evaluate(mp)
+            (primal, dual, primal_avg), host = self._evaluate(mp,
+                                                              compiles0)
             f_end = dual
             yield TraceRow(
                 it, int(mp.inner.n_exact), int(mp.inner.n_approx),
@@ -548,8 +552,7 @@ class Solver:
                 ws_mean, n_approx_passes,
                 led1[0] - led0[0], led1[2] - led0[2],
                 cache_hit_rate=hit_rate, planes_evicted=evicted,
-                oracle_share=oracle_share, oracle_overlap=oracle_overlap,
-                **gap_kw)
+                oracle_overlap=oracle_overlap, **gap_kw, **host)
 
     # -- serving export -----------------------------------------------------
 
@@ -584,7 +587,6 @@ class Solver:
         step = self._it if step is None else int(step)
         pack = getattr(self.engine, "pack_state", None)
         tree = pack(self._state) if pack is not None else self._state
-        import dataclasses
 
         extra = {
             "algo": self.cfg.algo,
@@ -659,7 +661,12 @@ class Solver:
         solver._state = unpack(tree) if unpack is not None else tree
         solver._it = int(extra.get("iteration", manifest["step"]))
         if extra.get("last_row") is not None:
-            solver._last_row = TraceRow(**extra["last_row"])
+            # Only the columns TraceRow still has: a row saved by an
+            # older release may hold columns since removed, and columns
+            # added since take their defaults.
+            known = {f.name for f in dataclasses.fields(TraceRow)}
+            solver._last_row = TraceRow(**{
+                k: v for k, v in extra["last_row"].items() if k in known})
         if "rng_state" in extra:
             solver._rng.set_state(_rng_state_from_json(extra["rng_state"]))
         now = float(extra.get("clock_now", 0.0))

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from .. import cache as plane_cache
 from ..cache import NEG_INF, PlaneCache
+from ..obs import spans
 from .averaging import update_average
 from .types import AveragingState, BCFWState
 
@@ -112,7 +113,9 @@ def approx_pass_gram(inner: BCFWState, cache: PlaneCache,
         av = update_average(av, st.phi, exact=False)
         return (st, c, av), None
 
-    (inner, cache, avg), _ = jax.lax.scan(body, (inner, cache, avg), perm)
+    with spans.scope(spans.APPROX_PASS):
+        (inner, cache, avg), _ = jax.lax.scan(body, (inner, cache, avg),
+                                              perm)
     return inner, cache, avg
 
 

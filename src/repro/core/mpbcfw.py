@@ -41,6 +41,7 @@ import jax.numpy as jnp
 
 from .. import cache as plane_cache
 from ..cache import CacheLayout, PlaneCache
+from ..obs import spans
 from .averaging import update_average
 from .bcfw import block_update
 from .selection import slope_continue_jnp
@@ -76,7 +77,8 @@ def exact_pass(problem: SSVMProblem, mp: MPState, perm: jnp.ndarray,
     def body(carry, i):
         st, c, av = carry
         w = weights_of(st.phi, lam)
-        phi_hat = problem.oracle(w, _example(problem, i))
+        with spans.scope(spans.ORACLE):
+            phi_hat = problem.oracle(w, _example(problem, i))
         if track_gap:
             # True block duality gap at the pre-update iterate: the exact
             # oracle's score minus the current convex combination's.
@@ -91,8 +93,9 @@ def exact_pass(problem: SSVMProblem, mp: MPState, perm: jnp.ndarray,
         av = update_average(av, st.phi, exact=True)
         return (st, c, av), None
 
-    (inner, cache, avg), _ = jax.lax.scan(body, (mp.inner, mp.cache, mp.avg),
-                                          perm)
+    with spans.scope(spans.EXACT_PASS):
+        (inner, cache, avg), _ = jax.lax.scan(
+            body, (mp.inner, mp.cache, mp.avg), perm)
     return MPState(inner=inner, cache=cache, avg=avg, outer_it=mp.outer_it)
 
 
@@ -125,8 +128,9 @@ def approx_pass(problem: Optional[SSVMProblem], mp: MPState,
         av = update_average(av, st.phi, exact=False)
         return (st, c, av), None
 
-    (inner, cache, avg), _ = jax.lax.scan(body, (mp.inner, mp.cache, mp.avg),
-                                          perm)
+    with spans.scope(spans.APPROX_PASS):
+        (inner, cache, avg), _ = jax.lax.scan(
+            body, (mp.inner, mp.cache, mp.avg), perm)
     return MPState(inner=inner, cache=cache, avg=avg, outer_it=mp.outer_it)
 
 
@@ -136,9 +140,10 @@ def begin_iteration(mp: MPState, ttl: int, eviction=None) -> MPState:
     ``eviction`` is an optional :class:`repro.policy.EvictionPolicy`;
     ``None`` keeps the paper's TTL rule with the explicit ``ttl``.
     """
-    it = mp.outer_it + 1
-    cache = (plane_cache.evict_stale(mp.cache, it, ttl)
-             if eviction is None else eviction.evict(mp.cache, it))
+    with spans.scope(spans.EVICT):
+        it = mp.outer_it + 1
+        cache = (plane_cache.evict_stale(mp.cache, it, ttl)
+                 if eviction is None else eviction.evict(mp.cache, it))
     return mp._replace(cache=cache, outer_it=it)
 
 

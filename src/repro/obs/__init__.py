@@ -12,29 +12,30 @@ so telemetry is a first-class subsystem, not a side effect:
     per-iteration host sync — the 1-dispatch + 1-host-sync contract is
     untouched, and ``repro.analysis`` re-proves it statically (rule
     J006 + the collective/host-callback budgets);
-  * :class:`RunRecorder` — structured spans and events (outer
-    iteration, exact pass, approximate multi-pass loop, eviction,
-    checkpoint save/restore, collective totals) written as JSONL, with
-    Chrome-trace/Perfetto export and optional
-    ``jax.profiler.StepTraceAnnotation`` hooks.  A
-    :class:`repro.api.Solver` installs it as a callback
-    (``Solver(..., recorder=RunRecorder(path))``);
+  * :mod:`repro.obs.spans` — the program's tracing, always on and
+    observe-only: ``repro:*`` host spans (``TraceAnnotation``) in the
+    Solver loop and the serving round, device scopes
+    (``jax.named_scope``) in the shared pass functions, and a
+    process-wide compile counter.  They write to the profiler's own
+    trace (``jax.profiler.trace``), on the device's clock;
+  * :class:`RunRecorder` — rows, spans and events (outer iteration,
+    eviction, checkpoint save/restore, collective totals) written as
+    JSONL.  A :class:`repro.api.Solver` installs it as a callback
+    (``Solver(..., recorder=RunRecorder(path))``); it observes only;
   * the CLI — ``python -m repro.obs run.jsonl`` summarizes a run
     (oracle calls to target gap, cache hit/evict rates, sync and
     collective budgets vs the engine's declared
-    :class:`~repro.api.engine.EngineCapabilities`, per-phase time
-    breakdown) and ``--diff`` compares two runs for regressions.
+    :class:`~repro.api.engine.EngineCapabilities`, evaluation and
+    checkpoint time) and ``--diff`` compares two runs for regressions.
 """
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry  # noqa: F401
 from .recorder import RunRecorder  # noqa: F401
 from .schema import SCHEMA_VERSION, validate_file, validate_record  # noqa: F401
 from .summary import (diff_runs, load_run, summarize,  # noqa: F401
                       summarize_run)
-from .trace_export import export_chrome_trace, to_chrome_trace  # noqa: F401
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "RunRecorder",
     "SCHEMA_VERSION", "validate_record", "validate_file",
     "load_run", "summarize", "summarize_run", "diff_runs",
-    "to_chrome_trace", "export_chrome_trace",
 ]

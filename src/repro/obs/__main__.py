@@ -3,7 +3,6 @@
     python -m repro.obs run.jsonl                    # summarize a run
     python -m repro.obs --diff a.jsonl b.jsonl       # compare two runs
     python -m repro.obs --validate run.jsonl         # schema check
-    python -m repro.obs --export-trace run.jsonl -o trace.json  # Perfetto
     python -m repro.obs --smoke-run out.jsonl --algo mpbcfw     # tiny run
 
 ``--smoke-run`` drives a small deterministic (CostModel-clocked) Solver
@@ -44,16 +43,12 @@ def _smoke_run(out_path: str, algo: str, seed: int, iters: int) -> int:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.obs",
-        description="Summarize, diff, validate, and export obs run traces.")
+        description="Summarize, diff and validate obs run traces.")
     ap.add_argument("runs", nargs="*", help="run JSONL file(s)")
     ap.add_argument("--diff", action="store_true",
                     help="diff two runs (requires exactly two files)")
     ap.add_argument("--validate", action="store_true",
                     help="validate the JSONL against the schema")
-    ap.add_argument("--export-trace", action="store_true",
-                    help="write a Chrome-trace/Perfetto JSON")
-    ap.add_argument("-o", "--out", default=None,
-                    help="output path for --export-trace")
     ap.add_argument("--smoke-run", action="store_true",
                     help="produce a tiny recorded run at RUNS[0] (CI)")
     ap.add_argument("--algo", default="mpbcfw",
@@ -85,15 +80,6 @@ def main(argv=None) -> int:
             else:
                 print(f"{path}: {count} records, schema OK")
         return status
-
-    if args.export_trace:
-        from .trace_export import export_chrome_trace
-
-        if len(args.runs) != 1 or not args.out:
-            ap.error("--export-trace needs one run file and -o OUT")
-        n = export_chrome_trace(args.runs[0], args.out)
-        print(f"{args.out}: {n} trace events")
-        return 0
 
     if args.diff:
         if len(args.runs) != 2:

@@ -177,7 +177,6 @@ class MetricsRegistry:
         self.gauge("gap").set(row.gap)
         self.gauge("cache_hit_rate").set(
             getattr(row, "cache_hit_rate", 0.0))
-        self.gauge("oracle_share").set(getattr(row, "oracle_share", 1.0))
         self.gauge("ws_mean").set(row.ws_mean)
         dt = row.time - (prev.time if prev else 0.0)
         if dt >= 0.0:

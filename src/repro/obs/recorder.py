@@ -7,24 +7,19 @@ host scalars the control loop already paid one sync for — and appends:
 
   * the row itself (plus cumulative collective count/bytes off the
     engine's :class:`~repro.core.selection.SyncLedger`),
-  * an ``outer_iteration`` span split into ``exact_pass`` /
-    ``approx_passes`` sub-spans — from the Solver's measured
-    program-boundary segments when it supplies them
-    (:meth:`RunRecorder.observe_phases`, wall mode; also the source of
-    the exact/plane cost calibration the Solver reads back), else by the
-    row's modeled ``oracle_share``,
+  * an ``outer_iteration`` span on the run clock,
   * ``cache_evict`` / ``collectives`` events when they carry signal.
+
+The recorder observes only: attaching one changes nothing the Solver
+computes.  Where the time of an iteration goes (eviction, the exact
+pass, the oracle, the approximate passes, the evaluation) is measured
+by the profiler from :mod:`repro.obs.spans`, not split up here.
 
 Everything is written through :func:`repro.obs.schema.sanitize`, so the
 file is strict JSONL (NaN/Inf become null).  The recorder never touches
 device values: it adds zero host syncs, zero dispatches, and zero host
 callbacks to the traced programs — the contract ``repro.analysis``
 re-proves statically and ``tests/test_obs.py`` asserts off the ledger.
-
-``profile=True`` arms :meth:`step_annotation`, which the Solver enters
-around each outer iteration as a
-``jax.profiler.StepTraceAnnotation`` — so an on-demand device profile
-(``jax.profiler.trace``) gets per-iteration step markers for free.
 """
 from __future__ import annotations
 
@@ -41,10 +36,8 @@ from .schema import SCHEMA_VERSION, sanitize
 class RunRecorder:
     """JSONL run recorder + metrics registry owner (one file per run)."""
 
-    def __init__(self, path, *, profile: bool = False,
-                 registry: Optional[MetricsRegistry] = None):
+    def __init__(self, path, *, registry: Optional[MetricsRegistry] = None):
         self.path = str(path)
-        self.profile = bool(profile)
         self.registry = registry if registry is not None else \
             MetricsRegistry()
         self._fh = open(self.path, "w", encoding="utf-8")
@@ -52,15 +45,6 @@ class RunRecorder:
         self._closed = False
         self._prev_time = 0.0
         self._led_prev = None  # (collectives, collective_bytes) snapshot
-        # Phase-cost calibration from measured program-boundary segments
-        # (wall mode; Solver.observe_phases).  Segment 0 of an iteration
-        # spans the fused exact(+first approx batch) program; later
-        # segments are approx-only overflow continuations whose measured
-        # durations identify the per-plane cost with no pro-rata split.
-        self._phase_pending = None      # this iteration's segments
-        self._seg_first = []            # (plane_steps, duration) of seg 0
-        self._seg_approx = []           # approx-only continuation samples
-        self._phase_fit = None          # last (exact_cost, plane_cost)
 
     # -- plumbing -----------------------------------------------------------
 
@@ -149,113 +133,16 @@ class RunRecorder:
                    collectives=coll, collective_bytes=nbytes)
         self._write(rec)
 
-        # Phase spans on the run clock.  Default: the iteration interval
-        # split by the modeled oracle share (wall-clock mode cannot time
-        # the phases individually without adding a sync per phase — which
-        # is exactly what this subsystem refuses to do).  When the Solver
-        # handed over measured program-boundary segments
-        # (:meth:`observe_phases`), those replace the pro-rata split:
-        # segment 0 still needs a modeled sub-split (exact and first
-        # approx batch share one fused program), but it uses the
-        # *calibrated* constants, and every overflow continuation is a
-        # genuinely measured approx-only span.
         t0, t1 = self._prev_time, float(row.time)
         self._prev_time = t1
         it = int(row.iteration)
         self.span_record("outer_iteration", t0, t1, iteration=it)
-        seg, self._phase_pending = self._phase_pending, None
-        if seg:
-            p0, d0 = seg[0]
-            if self._phase_fit is not None:
-                exact, plane = self._phase_fit
-                tot = exact + plane * p0
-                share = exact / tot if tot > 0.0 else 1.0
-            else:
-                share = min(max(float(getattr(row, "oracle_share", 1.0)),
-                                0.0), 1.0)
-            t_mid = t0 + share * d0
-            self.span_record("exact_pass", t0, t_mid, iteration=it)
-            if row.approx_passes > 0:
-                self.span_record("approx_passes", t_mid, t0 + d0,
-                                 iteration=it,
-                                 passes=int(row.approx_passes))
-            t_cur = t0 + d0
-            for planes, dur in seg[1:]:
-                self.span_record("approx_passes", t_cur, t_cur + dur,
-                                 iteration=it, planes=int(planes),
-                                 measured=True)
-                t_cur += dur
-        else:
-            share = min(max(float(getattr(row, "oracle_share", 1.0)),
-                            0.0), 1.0)
-            t_mid = t0 + share * (t1 - t0)
-            self.span_record("exact_pass", t0, t_mid, iteration=it)
-            if row.approx_passes > 0:
-                self.span_record("approx_passes", t_mid, t1, iteration=it,
-                                 passes=int(row.approx_passes))
         evicted = int(getattr(row, "planes_evicted", 0))
         if evicted > 0:
             self.event("cache_evict", t=t0, iteration=it, count=evicted)
         if d_coll > 0:
             self.event("collectives", t=t1, iteration=it, count=d_coll,
                        bytes=d_bytes)
-
-    # -- phase-cost calibration (wall mode) ---------------------------------
-
-    def observe_phases(self, segments):
-        """Consume one iteration's measured program-boundary segments.
-
-        ``segments`` is ``[(plane_steps, duration), ...]`` where entry 0
-        spans the iteration's fused exact(+first approx batch) program
-        and later entries are approx-only overflow continuations — the
-        Solver timestamps the host syncs it already pays for, so this
-        adds zero syncs.  Returns the current ``(exact_cost,
-        plane_cost)`` calibration, or ``None`` while unidentifiable (the
-        caller then keeps its previous constants instead of re-deriving
-        them pro-rata — the attribution-drift fix).
-        """
-        segs = [(float(p), float(d)) for p, d in segments]
-        self._phase_pending = segs
-        if segs:
-            self._seg_first.append(segs[0])
-            self._seg_approx.extend(s for s in segs[1:] if s[1] > 0.0)
-        self._phase_fit = self._fit_phase_costs()
-        return self._phase_fit
-
-    def _fit_phase_costs(self):
-        """(exact_cost, plane_cost) from the recorded segment series.
-
-        Preferred: continuation segments contain *only* approximate
-        passes, so ``plane_cost = sum(dur)/sum(planes)`` over them is a
-        direct measurement; the exact cost is then the mean first-segment
-        remainder.  Without continuations yet, fall back to least squares
-        of first-segment duration ~ exact + plane * steps over the full
-        recorded series (identifiable once plane counts vary)."""
-        first = self._seg_first[-32:]
-        cont = self._seg_approx[-32:]
-        if cont:
-            den = sum(p for p, _ in cont)
-            plane = (sum(d for _, d in cont) / den) if den > 0.0 else 0.0
-            if plane > 0.0 and first:
-                rems = [max(d - plane * p, 0.0) for p, d in first]
-                exact = sum(rems) / len(rems)
-                if exact > 0.0:
-                    return exact, plane
-            return self._phase_fit
-        if len(first) < 2:
-            return self._phase_fit
-        xs = [p for p, _ in first]
-        ys = [d for _, d in first]
-        mx = sum(xs) / len(xs)
-        my = sum(ys) / len(ys)
-        var = sum((x - mx) ** 2 for x in xs)
-        if var <= 0.0:
-            return self._phase_fit
-        b = sum((x - mx) * (y - my) for x, y in zip(xs, ys)) / var
-        a = my - b * mx
-        if a <= 0.0 or b <= 0.0:
-            return self._phase_fit
-        return a, b
 
     # -- spans / events (host-side phases) ----------------------------------
 
@@ -278,15 +165,3 @@ class RunRecorder:
         finally:
             self.span_record(name, t0, self._host_now(), timebase="host",
                              **attrs)
-
-    # -- profiler hooks -----------------------------------------------------
-
-    def step_annotation(self, step: int):
-        """Context the Solver enters around one outer iteration; a real
-        ``StepTraceAnnotation`` only under ``profile=True`` so the
-        default recorder adds nothing to the dispatch path."""
-        if not self.profile:
-            return contextlib.nullcontext()
-        import jax.profiler
-        return jax.profiler.StepTraceAnnotation("outer_iteration",
-                                                step_num=int(step))

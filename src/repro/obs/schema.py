@@ -12,8 +12,8 @@ Record types (``"type"`` discriminates):
                   cache program this iteration (0.0 on serial engines;
                   rule J009 proves the two-program structure statically).
   * ``span``    — a timed phase ``[t0, t1)``: ``outer_iteration``,
-                  ``exact_pass``, ``approx_passes``, ``checkpoint_save``,
-                  ``checkpoint_restore``.  ``timebase`` says which clock
+                  ``checkpoint_save``, ``checkpoint_restore``,
+                  ``serve_round``.  ``timebase`` says which clock
                   the endpoints are on: ``run`` (the solver's wall or
                   CostModel clock) or ``host`` (recorder wall time).
   * ``event``   — a point occurrence: ``cache_evict`` (count > 0),
@@ -46,7 +46,7 @@ _REQUIRED = {
             "ws_mean": _NUM, "approx_passes": (int,),
             "host_syncs": (int,), "dispatches": (int,),
             "cache_hit_rate": _NUM, "planes_evicted": (int,),
-            "oracle_share": _NUM, "oracle_overlap": _NUM,
+            "oracle_overlap": _NUM,
             "gap_total": _NUM + (type(None),), "gap_sampled": (int,),
             "collectives": (int,), "collective_bytes": (int,)},
     "span": {"name": (str,), "t0": _NUM, "t1": _NUM, "timebase": (str,)},

@@ -3,9 +3,9 @@
 ``summarize`` condenses a run into the paper's own accounting: exact
 oracle calls to reach gap targets (the Fig. 4-6 statistic), cache
 hit/evict rates, the host-sync / dispatch / collective ledger versus the
-engine's declared budgets, and a per-phase time breakdown from the
-spans.  ``diff_runs`` compares two summaries for regression checks — the
-CLI (`python -m repro.obs`) prints both.
+engine's declared budgets, the evaluation's wall time, and the
+checkpoint phases from the spans.  ``diff_runs`` compares two summaries
+for regression checks — the CLI (`python -m repro.obs`) prints both.
 """
 from __future__ import annotations
 
@@ -88,8 +88,10 @@ def summarize(run: dict) -> dict:
                                     for r in rows)
     s["approx_passes_mean"] = (sum(r.get("approx_passes", 0)
                                    for r in rows) / len(rows))
-    shares = [r.get("oracle_share", 1.0) for r in rows]
-    s["oracle_share_mean"] = sum(shares) / len(shares)
+    # Wall seconds of the evaluations, which the run clock leaves out
+    # (a CostModel run does not measure them).
+    s["eval_s_total"] = (sum(r.get("eval_s", 0.0) for r in rows)
+                         if meta.get("time_mode") == "wall" else None)
     # Pipelining efficiency (async engines; 0.0 everywhere else):
     # fraction of modeled oracle time hidden behind the cache program.
     overlaps = [r.get("oracle_overlap", 0.0) for r in rows]
@@ -114,19 +116,12 @@ def summarize(run: dict) -> dict:
             budgets.get("collectives_per_pass", 0) > 0 or coll_total == 0),
     }
 
-    # Per-phase time breakdown from the spans (run timebase).
-    phase: Dict[str, float] = {}
-    for sp in run["spans"]:
-        if sp.get("timebase") != "run" or sp["name"] == "outer_iteration":
-            continue
-        phase[sp["name"]] = (phase.get(sp["name"], 0.0)
-                             + max(sp["t1"] - sp["t0"], 0.0))
+    # Host-side phases (checkpoint save/restore) from the spans.
     host_phase: Dict[str, float] = {}
     for sp in run["spans"]:
         if sp.get("timebase") == "host":
             host_phase[sp["name"]] = (host_phase.get(sp["name"], 0.0)
                                       + max(sp["t1"] - sp["t0"], 0.0))
-    s["phase_time"] = phase
     s["host_phase_time"] = host_phase
     return s
 
@@ -159,7 +154,8 @@ def format_summary(s: dict) -> str:
             f"(mean)   planes evicted: {s.get('planes_evicted_total')}",
             f"approx passes:     {_fmt(s.get('approx_passes_mean'))} "
             f"per iteration (mean)",
-            f"oracle wall share: {_fmt(s.get('oracle_share_mean'))} (mean)",
+            f"evaluation:        {_fmt(s.get('eval_s_total'))} s "
+            f"(wall, outside the run clock)",
             f"oracle overlap:    {_fmt(s.get('oracle_overlap_mean'))} "
             f"(mean, async pipelining)",
         ]
@@ -172,8 +168,6 @@ def format_summary(s: dict) -> str:
             f"bytes={c.get('collective_bytes_total')}",
             f"  declared budgets: {c.get('declared_budgets')}",
         ]
-        for name, t in sorted((s.get("phase_time") or {}).items()):
-            lines.append(f"  phase {name}: {_fmt(t)} s")
         for name, t in sorted((s.get("host_phase_time") or {}).items()):
             lines.append(f"  host phase {name}: {_fmt(t)} s")
     return "\n".join(lines)
@@ -192,7 +186,7 @@ def _fmt(v) -> str:
 _DIFF_KEYS = ("iterations", "oracle_calls", "approx_calls", "final_gap",
               "final_dual", "total_time", "cache_hit_rate_mean",
               "planes_evicted_total", "approx_passes_mean",
-              "oracle_share_mean", "oracle_overlap_mean")
+              "eval_s_total", "oracle_overlap_mean")
 
 
 def diff_runs(run_a: dict, run_b: dict) -> dict:

@@ -14,7 +14,10 @@ per-example ``spec.decode`` (the round-trip tests pin this).
 
 Round structure is *asserted*, not hoped for: the
 :class:`~repro.serve.metrics.ServeLedger` brackets each round and raises
-unless it dispatched exactly once.  Latency/queue/throughput series ride
+unless it dispatched exactly once.  Each round is a ``repro:round``
+profiler span (:mod:`repro.obs.spans`) holding ``pick``, ``pad``,
+``stack``, ``decode``, ``sync`` and ``answer`` spans.
+Latency/queue/throughput series ride
 :class:`~repro.serve.metrics.ServeMetrics`, and an optional
 :class:`~repro.obs.recorder.RunRecorder` gets schema-v1 ``serve_round``
 spans + per-request events, so serving traces replay through the same
@@ -29,6 +32,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs import spans
 from .engine import DecodeEngine, ShapeKey, decode_engine_for
 from .export import ServableModel
 from .metrics import ServeLedger, ServeMetrics
@@ -131,30 +135,44 @@ class StructuredServer:
 
         Returns the completed requests of the round ([] when idle).
         """
-        bucket = self._pick_bucket()
-        if bucket is None:
-            return []
-        queue = self._queues[bucket]
-        reqs = queue[: self.batch_size]
-        del queue[: len(reqs)]
-        if not queue:
-            del self._queues[bucket]
+        with spans.span(spans.ROUND) as round_span:
+            with spans.span(spans.PICK):
+                bucket = self._pick_bucket()
+                if bucket is None:
+                    return []
+                queue = self._queues[bucket]
+                reqs = queue[: self.batch_size]
+                del queue[: len(reqs)]
+                if not queue:
+                    del self._queues[bucket]
+            round_span.set_metadata(bucket=",".join(map(str, bucket)),
+                                    batch=len(reqs))
 
-        t0 = self.clock()
-        padded = [self.engine.pad(r.example, bucket) for r in reqs]
-        # Filler rows keep the batch shape fixed so the bucket's compiled
-        # executable is reused; rows decode independently, so fillers
-        # cannot perturb the real rows.
-        padded.extend([padded[-1]] * (self.batch_size - len(padded)))
-        batch = self.engine.stack(padded)
+            t0 = self.clock()
+            with spans.span(spans.PAD):
+                padded = [self.engine.pad(r.example, bucket) for r in reqs]
+                # Filler rows keep the batch shape fixed so the bucket's
+                # compiled executable is reused; rows decode
+                # independently, so fillers cannot perturb the real rows.
+                padded.extend([padded[-1]] * (self.batch_size - len(padded)))
+            with spans.span(spans.STACK):
+                batch = self.engine.stack(padded)
 
-        self.ledger.begin_round()
-        out = self.engine.decode(batch)
-        self.ledger.dispatched()
-        labels = self.ledger.sync(out)
-        self.ledger.commit_round()
+            self.ledger.begin_round()
+            with spans.span(spans.DECODE):
+                out = self.engine.decode(batch)
+            self.ledger.dispatched()
+            with spans.span(spans.SYNC):
+                labels = self.ledger.sync(out)
+            self.ledger.commit_round()
 
-        t1 = self.clock()
+            t1 = self.clock()
+            with spans.span(spans.ANSWER):
+                self._answer(reqs, labels, bucket, t0, t1)
+        return reqs
+
+    def _answer(self, reqs, labels, bucket, t0: float, t1: float) -> None:
+        """Unpad each request's labels and record the round."""
         for i, req in enumerate(reqs):
             req.labels = np.asarray(self.engine.unpad(labels[i], req.key))
             req.t_done = t1
@@ -173,7 +191,6 @@ class StructuredServer:
                                       bucket=list(bucket),
                                       batch=len(reqs),
                                       slots=self.batch_size)
-        return reqs
 
     def drain(self) -> List[ServeRequest]:
         """Run rounds until every admitted request is served."""

@@ -1,4 +1,4 @@
-"""repro.obs: recorder wiring, schema, metrics, export, and the
+"""repro.obs: recorder wiring, schema, metrics, and the
 sync-contract / checkpoint guarantees the obs layer must not break.
 
 The load-bearing assertions:
@@ -9,11 +9,14 @@ The load-bearing assertions:
   * the on-device ObsMetrics drain produces real hit/evict numbers with
     zero extra host work;
   * CostModel/wall calibration constants and the metrics registry
-    survive a checkpoint round trip bit for bit;
+    survive a checkpoint round trip bit for bit, and attaching a
+    recorder changes none of them;
   * CollectiveTrace raises a clear RuntimeError when used outside a
     begin()/commit() window (regression: used to be an AttributeError).
 """
+import dataclasses
 import json
+import types
 
 import numpy as np
 import pytest
@@ -22,9 +25,8 @@ from repro.api import RunConfig, Solver
 from repro.checkpoint.manager import CheckpointManager
 from repro.core.selection import CostModel
 from repro.obs import (MetricsRegistry, RunRecorder, diff_runs, load_run,
-                       summarize, summarize_run, to_chrome_trace,
-                       validate_file, validate_record)
-from repro.obs.trace_export import export_chrome_trace
+                       summarize, summarize_run, validate_file,
+                       validate_record)
 from repro.shard.telemetry import CollectiveTrace
 
 
@@ -109,13 +111,12 @@ def test_on_device_metrics_measure_eviction(multiclass_problem):
     assert all(r.host_syncs == 1 for r in res.trace)
     assert any(r.planes_evicted > 0 for r in res.trace)
     assert all(0.0 <= r.cache_hit_rate <= 1.0 for r in res.trace)
-    assert all(0.0 < r.oracle_share <= 1.0 for r in res.trace)
     # Single-block inserts bound the hit rate by occupancy/n.
     assert res.trace[0].cache_hit_rate <= 1.0
 
 
 # ---------------------------------------------------------------------------
-# Recorder output: schema, summary, diff, Perfetto export
+# Recorder output: schema, summary, diff
 
 
 def test_recorder_jsonl_schema_and_summary(tmp_path, multiclass_problem):
@@ -130,7 +131,7 @@ def test_recorder_jsonl_schema_and_summary(tmp_path, multiclass_problem):
     assert run["meta"]["algo"] == "mpbcfw"
     assert "engine_budgets" in run["meta"]
     assert len(run["rows"]) == 5
-    assert any(sp["name"] == "exact_pass" for sp in run["spans"])
+    assert [sp["name"] for sp in run["spans"]] == ["outer_iteration"] * 5
 
     s = summarize(run)
     assert s["iterations"] == 5
@@ -142,13 +143,6 @@ def test_recorder_jsonl_schema_and_summary(tmp_path, multiclass_problem):
 
     d = diff_runs(run, run)
     assert d["deltas"]["final_gap"]["delta"] == 0.0
-
-    out = tmp_path / "trace.json"
-    n = export_chrome_trace(str(path), str(out))
-    events = json.loads(out.read_text())["traceEvents"]
-    assert len(events) == n
-    assert any(e["ph"] == "X" for e in events)
-    assert any(e["ph"] == "C" for e in events)
 
 
 def test_schema_rejects_bad_records():
@@ -241,6 +235,41 @@ def test_checkpoint_calibration_bitwise_resume(tmp_path,
     assert s2.metrics.snapshot() == s1.metrics.snapshot()
 
 
+@pytest.mark.parametrize("saved_by", ["this_release", "older_release"])
+def test_restore_rebuilds_last_row_from_any_release(tmp_path,
+                                                    multiclass_problem,
+                                                    saved_by):
+    """A manifest's ``last_row`` restores whichever release wrote it: an
+    older one stored ``oracle_share`` (since removed) and no ``eval_s``
+    or ``compiles`` (since added, which then take their defaults)."""
+    prob = multiclass_problem
+
+    def cfg():
+        return RunConfig(lam=0.05, algo="mpbcfw", cap=8, max_iters=4,
+                         max_approx_passes=4, approx_batch=4, seed=2)
+
+    mgr = CheckpointManager(str(tmp_path / "ckpt"))
+    s1 = Solver(prob, cfg())
+    it = s1.iterate()
+    for _ in range(2):
+        next(it)
+    step = s1.save(mgr)
+    want = dataclasses.asdict(s1._last_row)
+    if saved_by == "older_release":
+        path = mgr._step_dir(step) / "manifest.json"
+        manifest = json.loads(path.read_text())
+        row = manifest["extra"]["last_row"]
+        del row["eval_s"], row["compiles"]
+        row["oracle_share"] = 0.75
+        path.write_text(json.dumps(manifest))
+        want.update(eval_s=0.0, compiles=0)
+
+    s2 = Solver.restore(prob, cfg(), mgr)
+    assert dataclasses.asdict(s2._last_row) == want
+    rest = list(s2.iterate())                  # and the run goes on
+    assert [r.iteration for r in rest] == [2, 3]
+
+
 def test_checkpoint_save_restore_spans_recorded(tmp_path,
                                                 multiclass_problem):
     prob = multiclass_problem
@@ -256,84 +285,45 @@ def test_checkpoint_save_restore_spans_recorded(tmp_path,
 
 
 # ---------------------------------------------------------------------------
-# Chrome-trace export unit (no solver run needed)
+# The recorder observes only: with a scripted wall clock, a Solver with a
+# recorder and one without calibrate the slope rule identically
 
 
-def test_to_chrome_trace_shapes():
-    records = [
-        {"type": "meta", "schema_version": 1, "algo": "mpbcfw", "n": 4,
-         "time_mode": "cost_model"},
-        {"type": "span", "name": "exact_pass", "t0": 0.0, "t1": 1.0,
-         "timebase": "run", "iteration": 0},
-        {"type": "event", "name": "cache_evict", "t": 0.5,
-         "iteration": 0, "data": {"planes": 3}},
-        {"type": "row", "iteration": 0, "time": 1.0, "dual": 0.1,
-         "gap": 0.9, "n_exact": 4, "n_approx": 8, "host_syncs": 1,
-         "dispatches": 1},
-    ]
-    events = to_chrome_trace(records)["traceEvents"]
-    phs = {e["ph"] for e in events}
-    assert {"X", "i", "C", "M"} <= phs
-    span = next(e for e in events if e["ph"] == "X")
-    assert span["dur"] == pytest.approx(1e6)  # seconds -> microseconds
+def _scripted_clock(script):
+    """A ``time`` stand-in whose ``perf_counter`` advances by the
+    script's steps in turn (cycling), from 0."""
+    state = {"t": 0.0, "k": 0}
+
+    def perf_counter():
+        state["t"] += script[state["k"] % len(script)]
+        state["k"] += 1
+        return state["t"]
+    return types.SimpleNamespace(perf_counter=perf_counter)
 
 
-# ---------------------------------------------------------------------------
-# Phase-cost calibration from measured program-boundary segments
-# (wall mode: overflow continuations identify the per-plane cost
-# directly; the exact cost is the mean first-segment remainder)
+@pytest.mark.parametrize("script", [(0.01,), (0.002, 0.05, 0.001),
+                                    (0.3, 0.001, 0.02, 0.007)],
+                         ids=["steady", "bursty", "uneven"])
+def test_recorder_leaves_wall_calibration_unchanged(tmp_path, monkeypatch,
+                                                    multiclass_problem,
+                                                    script):
+    """Wall mode: a recorder changes neither the slope rule's cost
+    constants nor the pass schedule they drive (approx_batch <
+    max_approx_passes, so overflow continuations run too)."""
+    from repro.api import solver as solver_mod
 
+    def run(recorder):
+        monkeypatch.setattr(solver_mod, "time", _scripted_clock(script))
+        solver = Solver(multiclass_problem,
+                        _cfg("mpbcfw", cost_model=None, max_iters=4,
+                             approx_batch=2, max_approx_passes=8),
+                        recorder=recorder)
+        rows = solver.run().trace
+        return ((solver._est_exact, solver._est_plane),
+                [(r.n_exact, r.n_approx, r.approx_passes, r.dual)
+                 for r in rows])
 
-def test_observe_phases_calibrates_from_continuations(tmp_path):
-    with RunRecorder(str(tmp_path / "cal.jsonl")) as rec:
-        # first segment = exact(2.0) + 8 planes * 0.25; two approx-only
-        # continuations at exactly 0.25 per plane
-        fit = rec.observe_phases([(8, 4.0), (4, 1.0), (6, 1.5)])
-        assert fit is not None
-        exact, plane = fit
-        assert plane == pytest.approx(0.25)
-        assert exact == pytest.approx(4.0 - 8 * 0.25)
-
-
-def test_observe_phases_least_squares_without_continuations(tmp_path):
-    with RunRecorder(str(tmp_path / "cal.jsonl")) as rec:
-        # no overflow continuations: identifiable once the first-segment
-        # plane counts vary (duration = 1.5 + 0.1 * planes)
-        assert rec.observe_phases([(10, 2.5)]) is None
-        fit = rec.observe_phases([(30, 4.5)])
-        assert fit is not None
-        exact, plane = fit
-        assert exact == pytest.approx(1.5)
-        assert plane == pytest.approx(0.1)
-
-
-def test_observe_phases_keeps_last_fit_when_unidentifiable(tmp_path):
-    with RunRecorder(str(tmp_path / "cal.jsonl")) as rec:
-        good = rec.observe_phases([(8, 4.0), (4, 1.0)])
-        assert good == (pytest.approx(2.0), pytest.approx(0.25))
-        # a degenerate iteration (zero-length continuation, same first-
-        # segment shape) must not clobber the calibration
-        assert rec.observe_phases([(8, 4.0), (4, 0.0)]) == good
-
-
-def test_wall_mode_solver_adopts_recorder_calibration(tmp_path,
-                                                      multiclass_problem):
-    """Wall mode + recorder: the Solver's device-rule cost constants come
-    from the recorder's measured-segment fit (not the pro-rata
-    regression), and the recorder's phase spans use the same split."""
-    prob = multiclass_problem
-    path = tmp_path / "wall.jsonl"
-    with RunRecorder(str(path)) as rec:
-        # approx_batch < max_approx_passes forces overflow continuations
-        # — the approx-only segments the calibration measures directly
-        solver = Solver(prob, _cfg("mpbcfw", cost_model=None,
-                                   max_iters=4, approx_batch=2,
-                                   max_approx_passes=8), recorder=rec)
-        solver.run()
-        fit = rec._phase_fit
-        if fit is not None:
-            assert (solver._est_exact, solver._est_plane) == fit
-    run = load_run(str(path))
-    assert any(sp["name"] == "exact_pass" for sp in run["spans"])
-    assert any(sp.get("measured") for sp in run["spans"]
-               if sp["name"] == "approx_passes") or fit is None
+    bare = run(None)
+    with RunRecorder(str(tmp_path / "wall.jsonl")) as rec:
+        recorded = run(rec)
+    assert recorded == bare
