@@ -29,7 +29,10 @@ Evaluation (:func:`evaluate_objectives`: primal/dual/gap, n — 2n with
 averaging — extra oracle calls per iteration) is telemetry, **not** part
 of the control loop: its wall time is measured and subtracted from every
 clock reading (``_Clock.exclude``), reported in ``TraceRow.eval_s``, and
-its device fetches are not charged to the ledger.
+its device fetch is not charged to the ledger.  It is one jitted program,
+cached per oracle, ``n``, ``lam`` and whether averaging is on, whose
+three objectives come back in one fetch: after the first call it
+dispatches once and compiles nothing.
 
 Tracing (:mod:`repro.obs.spans`) is always on and observes only: each
 outer iteration is a profiler step ``repro:iteration`` holding
@@ -48,6 +51,7 @@ the uninterrupted one.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from contextlib import contextmanager, nullcontext
 from types import SimpleNamespace
@@ -64,7 +68,7 @@ from ..obs.metrics import MetricsRegistry
 
 if TYPE_CHECKING:  # annotation only
     from ..obs.recorder import RunRecorder
-from ..core.ssvm import batched_oracle, dual_value, weights_of
+from ..core.ssvm import dual_value, primal_value, weights_of
 from ..core.averaging import extract as extract_average
 from ..obs import spans
 from ..core.types import SSVMProblem
@@ -130,30 +134,41 @@ class _Clock:
         return self._wall()
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1),
+                   static_argnames=("lam", "certified"))
+def _objectives_program(oracle, n, data, x, avg, *, lam: float,
+                        certified: bool = True) -> jnp.ndarray:
+    """``[primal, dual, primal at the average]`` as one float32 ``(3,)``.
+
+    ``x`` is the dual vector ``phi``; with ``certified=False`` it is a raw
+    weight vector (SSG), whose dual is NaN.  ``avg=None`` (averaging
+    off) and an :class:`~repro.core.types.AveragingState` are two
+    pytree structures, hence two cached traces of this one program.
+    """
+    if certified:
+        w, dual = weights_of(x, lam), dual_value(x, lam)
+    else:
+        w, dual = x, jnp.full((), jnp.nan, jnp.float32)
+    prob = SSVMProblem(n=n, d=w.shape[0], data=data, oracle=oracle)
+    primal = primal_value(prob, w, lam)
+    primal_avg = primal if avg is None else primal_value(
+        prob, weights_of(extract_average(avg, lam), lam), lam)
+    return jnp.stack([primal, dual, primal_avg])
+
+
 def evaluate_objectives(problem: SSVMProblem, phi, avg, lam: float):
     """Primal/dual/gap (+ primal at the averaged iterate).  Not timed:
     callers wrap this in ``clock.exclude()``."""
-    w = weights_of(phi, lam)
-    planes = batched_oracle(problem, w)
-    hinge = jnp.sum(planes[:, :-1] @ w + planes[:, -1])
-    primal = 0.5 * lam * jnp.dot(w, w) + hinge
-    dual = dual_value(phi, lam)
-    if avg is not None:
-        phi_bar = extract_average(avg, lam)
-        w_bar = weights_of(phi_bar, lam)
-        planes_b = batched_oracle(problem, w_bar)
-        hinge_b = jnp.sum(planes_b[:, :-1] @ w_bar + planes_b[:, -1])
-        primal_avg = 0.5 * lam * jnp.dot(w_bar, w_bar) + hinge_b
-    else:
-        primal_avg = primal
-    return float(primal), float(dual), float(primal_avg)
+    out = _objectives_program(problem.oracle, problem.n, problem.data, phi,
+                              avg, lam=lam)
+    return tuple(float(v) for v in jax.device_get(out))  # the one fetch
 
 
 def ssg_primal(problem: SSVMProblem, w, lam: float) -> float:
     """Primal objective at a raw weight vector (no dual certificate)."""
-    planes = batched_oracle(problem, w)
-    return float(0.5 * lam * jnp.dot(w, w)
-                 + jnp.sum(planes[:, :-1] @ w + planes[:, -1]))
+    out = _objectives_program(problem.oracle, problem.n, problem.data, w,
+                              None, lam=lam, certified=False)
+    return float(jax.device_get(out)[0])
 
 
 def _fit_pass_costs(xs: List[float], ys: List[float]):

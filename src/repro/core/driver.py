@@ -33,7 +33,7 @@ _MOVED = {
     "_evaluate": ("repro.api.solver", "evaluate_objectives"),
     "_fit_pass_costs": ("repro.api.solver", "_fit_pass_costs"),
     "_draw_perms": ("repro.api.solver", "_draw_perms"),
-    "batched_oracle": ("repro.api.solver", "batched_oracle"),
+    "batched_oracle": ("repro.core.ssvm", "batched_oracle"),
 }
 
 

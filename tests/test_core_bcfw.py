@@ -466,21 +466,21 @@ def test_ws_mean_one_statistic_in_both_branches(multiclass_problem):
 
 def test_wall_clock_excludes_evaluation_time(multiclass_problem,
                                              monkeypatch):
-    """Regression: `_evaluate`'s batched_oracle sweeps (n exact oracle
-    calls per iteration) are "Not timed" — a deliberately slow oracle in
-    the evaluation path must not inflate TraceRow.time."""
+    """Regression: `_evaluate`'s oracle sweeps (n exact oracle calls per
+    iteration) are "Not timed" — a deliberately slow evaluation must not
+    inflate TraceRow.time."""
     from repro.api import solver as api_solver
 
     prob = multiclass_problem
     lam = 1.0 / prob.n
-    real = api_solver.batched_oracle
+    real = api_solver.evaluate_objectives
     sleep_s = 0.15
 
-    def slow_eval_oracle(problem, w):
+    def slow_evaluation(*a, **kw):
         time.sleep(sleep_s)
-        return real(problem, w)
+        return real(*a, **kw)
 
-    monkeypatch.setattr(api_solver, "batched_oracle", slow_eval_oracle)
+    monkeypatch.setattr(api_solver, "evaluate_objectives", slow_evaluation)
     iters = 3
     wall0 = time.perf_counter()
     res = _solver_run(prob, driver.RunConfig(

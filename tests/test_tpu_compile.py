@@ -120,3 +120,32 @@ def test_fused_ocr_iteration_fits_one_chip(one_chip, monkeypatch):
                       for a in jax.tree_util.tree_leaves(state))
     assert mem.alias_size_in_bytes >= 0.99 * state_bytes, mem
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("averaged", [False, True], ids=["plain", "avg"])
+def test_ocr_evaluation_fits_beside_the_cache(one_chip, monkeypatch,
+                                              averaged):
+    """The evaluation program (primal, dual, primal at the average) at
+    the paper's OCR size compiles for one chip into the HBM the plane
+    cache leaves, the state it reads staying live."""
+    from repro.api import solver
+    from repro.core.averaging import init_averaging
+
+    monkeypatch.setattr(ops, "use_pallas", lambda: True)
+    sc = paper.OCR
+    n, L, f = sc.n, sc.max_len, sc.f
+    d = sc.num_classes * (f + sc.num_classes)
+    data = {"x": _sds(one_chip, (n, L, f)),
+            "y": _sds(one_chip, (n, L), jnp.int32),
+            "mask": _sds(one_chip, (n, L), bool)}
+    avg = (jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: init_averaging(d))) if averaged else None)
+    compiled = solver._objectives_program.lower(
+        _tiny_ocr_oracle(), n, data, _sds(one_chip, (d + 1,)), avg,
+        lam=1.0 / n).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    cache_bytes = n * 64 * (d + 1) * 4
+    assert total <= V5E_HBM_BYTES - cache_bytes, mem
