@@ -9,7 +9,9 @@ paper's OCR chain task at its published size (n=6877, f=128, 26 labels,
 d=4004, cap=64; data generated from a fixed seed), check that the dual
 never falls and the duality gap shrinks, check that the fused program the
 engine dispatches holds a Pallas kernel and that the kernels agree with
-their ``repro.kernels.ref`` references on the chip, then serve 64 OCR
+their ``repro.kernels.ref`` references on the chip, run one approximate
+pass of the trained cache as the ``approx_block_steps`` kernel against a
+float64 replay on the host, then serve 64 OCR
 examples through ``StructuredServer`` and check every labeling against the
 per-example ``spec.decode``.
 
@@ -49,6 +51,11 @@ SERVE_BATCH = 8
 # room for bf16 rounding of the operands inside the MXU (2^-8), far below
 # what a dropped or misplaced tile would cost.
 SCORE_RTOL = 1e-2
+# One approximate pass over this many blocks of the trained cache, as the
+# approx_block_steps kernel, against a float64 replay on the host: the
+# kernel scores in float32 on the VPU, so only rounding separates them.
+APPROX_STEPS = 512
+APPROX_RTOL = 1e-4
 
 
 class Checks:
@@ -194,6 +201,70 @@ def check_kernels(solver, check: Checks, label: str) -> None:
               f"equal the reference ({int(np.sum(got != want))} differ)")
 
 
+def replay_approx_pass(planes, valid, phi, phi_i, perm, lam):
+    """The approximate pass in float64 on the host over blocks ``perm``
+    (rows of ``planes``, ``valid`` and ``phi_i``, indexed by position in
+    ``perm``'s distinct blocks): returns ``(phi, phi_i, slots)``."""
+    import numpy as np
+
+    phi, phi_i = phi.astype(np.float64), phi_i.astype(np.float64)
+    slots = []
+    for i in perm:
+        w = -phi[:-1] / lam
+        scores = np.where(valid[i], planes[i, :, :-1] @ w + planes[i, :, -1],
+                          -np.inf)
+        slot = int(np.argmax(scores))
+        hat = planes[i, slot] if valid[i].any() else np.zeros_like(phi)
+        diff = phi_i[i] - hat
+        den = diff[:-1] @ diff[:-1]
+        gamma = (np.clip((diff[:-1] @ phi[:-1] - lam * diff[-1]) / den, 0, 1)
+                 if den > 0 else 0.0)
+        new = (1 - gamma) * phi_i[i] + gamma * hat
+        phi, phi_i[i] = phi + new - phi_i[i], new
+        slots.append(slot)
+    return phi, phi_i, slots
+
+
+def check_approx_kernel(solver, check: Checks, label: str) -> None:
+    """One approximate pass over ``APPROX_STEPS`` blocks of the trained
+    state (one block taken twice in a row) through ``approx_pass``,
+    which runs the ``approx_block_steps`` kernel on the chip, against
+    :func:`replay_approx_pass`."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import mpbcfw
+
+    mp, lam = solver.state, solver.cfg.lam
+    perm = jax.random.permutation(jax.random.PRNGKey(7), solver.problem.n)
+    perm = perm[:APPROX_STEPS].at[1].set(perm[0]).astype(jnp.int32)
+    check(mpbcfw.approx_kernel_runs(mp.cache, APPROX_STEPS),
+          f"[{label}] approximate passes run the approx_block_steps kernel")
+    stamp = jnp.asarray(1 << 30, jnp.int32)
+    phi, rows, stamped = jax.jit(
+        lambda mp, p: (lambda s: (s.inner.phi, s.inner.phi_i[p],
+                                  s.cache.last_active[p] == stamp))(
+            mpbcfw.approx_pass(None, mp._replace(outer_it=stamp), p, lam)))(
+        mp, perm)
+    blocks, pos = np.unique(np.asarray(perm), return_inverse=True)
+    idx = jnp.asarray(blocks)
+    want_phi, want_rows, slots = replay_approx_pass(
+        np.asarray(mp.cache.planes[idx]), np.asarray(mp.cache.valid[idx]),
+        np.asarray(mp.inner.phi), np.asarray(mp.inner.phi_i[idx]), pos, lam)
+    chosen = np.zeros((len(blocks), mp.cache.valid.shape[1]), bool)
+    chosen[pos, slots] = True
+    err = max(float(np.max(np.abs(np.asarray(a, np.float64) - b))
+                    / np.max(np.abs(b)))
+              for a, b in ((phi, want_phi), (rows, want_rows[pos])))
+    differ = int(np.sum(np.asarray(stamped)[np.unique(pos, True)[1]]
+                        != chosen))
+    check(err <= APPROX_RTOL and differ == 0,
+          f"[{label}] approx_block_steps over {APPROX_STEPS} blocks vs a "
+          f"float64 replay: max error / scale {err!r} <= {APPROX_RTOL}, "
+          f"{differ} chosen slots differ")
+
+
 def serve(solver, check: Checks, label: str) -> None:
     """Serve the first ``SERVE_REQUESTS`` examples at their true lengths
     and compare each labeling with the per-example decode."""
@@ -236,6 +307,7 @@ def one_chip(sc, check: Checks) -> None:
     print_memory(label)
     check_dispatched_program(solver, check, label)
     check_kernels(solver, check, label)
+    check_approx_kernel(solver, check, label)
     serve(solver, check, label)
 
 

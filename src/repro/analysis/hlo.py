@@ -16,8 +16,9 @@ while-loop placement.  Invariants:
     single-device program) must compile to zero collective ops, full
     stop.
   * **H003** — the Pallas kernel tiling policies (plane_scores /
-    plane_select block shapes, the viterbi label padding) must produce
-    (8, 128)-aligned (sublane, lane) tiles for every shape.
+    plane_select block shapes, the viterbi label padding, the
+    approx_block_steps DMA tiles) must produce (8, 128)-aligned
+    (sublane, lane) tiles for every shape.
   * **H004** — every traced program must actually lower and compile.
 """
 from __future__ import annotations
@@ -143,6 +144,23 @@ def check_tiles() -> List[Finding]:
         if block_b % SUBLANE:
             bad("viterbi.py",
                 f"batch tile {block_b} not a multiple of {SUBLANE}")
+
+    # approx_block_steps DMAs whole tiles: TILE slots of a block (or TILE
+    # phi_i rows) by d+1 lanes padded as in HBM; a cap the tiles do not
+    # divide must leave the pass to the scan.
+    from ..kernels import approx_steps
+
+    for _, d in _TILE_SHAPES:
+        rows, lanes = approx_steps.tile_shape(d)
+        if rows % SUBLANE or lanes % LANE or lanes < d + 1:
+            bad("approx_steps.py",
+                f"tile_shape({d}) -> ({rows}, {lanes}) not "
+                f"({SUBLANE}, {LANE})-aligned over d+1 = {d + 1}")
+    for cap in (1, 7, 10, 12, 100):
+        if approx_steps.fits(64, cap, 128, 64):
+            bad("approx_steps.py",
+                f"fits() admits cap={cap}, which {approx_steps.TILE}-slot "
+                f"tiles do not divide")
     return findings
 
 

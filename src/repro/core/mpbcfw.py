@@ -41,6 +41,7 @@ import jax.numpy as jnp
 
 from .. import cache as plane_cache
 from ..cache import CacheLayout, PlaneCache
+from ..kernels import ops as kops
 from ..obs import spans
 from .averaging import update_average
 from .bcfw import block_update
@@ -99,15 +100,38 @@ def exact_pass(problem: SSVMProblem, mp: MPState, perm: jnp.ndarray,
     return MPState(inner=inner, cache=cache, avg=avg, outer_it=mp.outer_it)
 
 
+def approx_kernel_runs(cache: PlaneCache, steps: int) -> bool:
+    """Whether an approximate pass of ``steps`` block steps runs as the
+    ``approx_block_steps`` kernel: a cache without Gram blocks whose
+    shapes fit the kernel's plan, on a TPU (``kernels.ops``)."""
+    return cache.gram is None and kops.approx_kernel_fits(cache.planes,
+                                                          steps)
+
+
 def approx_pass(problem: Optional[SSVMProblem], mp: MPState,
-                perm: jnp.ndarray, lam: float) -> MPState:
+                perm: jnp.ndarray, lam: float, *,
+                occupancy=None) -> MPState:
     """Paper Alg. 3 step 4: BCFW pass against the cached planes only.
 
     Each step is monotone in F because the cached planes are genuine data
     planes (so the line search is valid), even though H~_i may locally sit
     below the convex combination phi_i (paper footnote 2).
+
+    Where :func:`approx_kernel_runs` holds, the pass is one
+    ``approx_block_steps`` launch doing the scan body's arithmetic in
+    float32; ``occupancy`` is its ``kernels.ops.approx_occupancy`` of the
+    cache, derived here unless a caller running several passes over one
+    cache passes it in.  Elsewhere the scan below runs.
     """
     del problem  # the approximate pass never touches the data
+    if approx_kernel_runs(mp.cache, perm.shape[0]):
+        return approx_pass_kernel(mp, perm, lam, occupancy)
+    return approx_pass_scan(mp, perm, lam)
+
+
+def approx_pass_scan(mp: MPState, perm: jnp.ndarray, lam: float) -> MPState:
+    """:func:`approx_pass` as a ``lax.scan`` of block steps (every
+    backend, every cache layout)."""
     track_gap = mp.cache.gap is not None
 
     def body(carry, i):
@@ -132,6 +156,29 @@ def approx_pass(problem: Optional[SSVMProblem], mp: MPState,
         (inner, cache, avg), _ = jax.lax.scan(
             body, (mp.inner, mp.cache, mp.avg), perm)
     return MPState(inner=inner, cache=cache, avg=avg, outer_it=mp.outer_it)
+
+
+def approx_pass_kernel(mp: MPState, perm: jnp.ndarray, lam: float,
+                       occupancy=None) -> MPState:
+    """:func:`approx_pass` as one ``approx_block_steps`` launch (a TPU,
+    where :func:`approx_kernel_runs` holds)."""
+    st, c, av = mp.inner, mp.cache, mp.avg
+    steps = perm.shape[0]
+    with spans.scope(spans.APPROX_PASS):
+        if occupancy is None:
+            occupancy = kops.approx_occupancy(c.valid)
+        phi, phi_i, bar, gap, slots = kops.approx_block_steps(
+            c.planes, c.valid, perm, st.phi, st.phi_i, av.bar_approx,
+            av.k_approx, c.gap, lam=lam, occupancy=occupancy)
+        # Every step stamps the same iteration, so repeated blocks make
+        # one scatter order-free.
+        c = plane_cache.mark_active(c, perm, slots, mp.outer_it)
+    return MPState(
+        inner=st._replace(phi=phi, phi_i=phi_i,
+                          n_approx=st.n_approx + steps),
+        cache=c if gap is None else c._replace(gap=gap),
+        avg=av._replace(bar_approx=bar, k_approx=av.k_approx + steps),
+        outer_it=mp.outer_it)
 
 
 def begin_iteration(mp: MPState, ttl: int, eviction=None) -> MPState:
@@ -275,6 +322,10 @@ def multi_approx_pass(mp: MPState, perms: jnp.ndarray, clock: SlopeClock,
     total_planes = jnp.sum(plane_cache.sizes(mp.cache)).astype(jnp.int32)
     cost = clock.plane_cost * jnp.maximum(total_planes, 1).astype(jnp.float32)
     use_gram = mp.cache.gram is not None
+    occupancy = None
+    if perms.shape[0] and approx_kernel_runs(mp.cache, perms.shape[1]):
+        with spans.scope(spans.APPROX_PASS):
+            occupancy = kops.approx_occupancy(mp.cache.valid)
 
     def step(state: MPState, perm: jnp.ndarray):
         if use_gram:
@@ -283,7 +334,8 @@ def multi_approx_pass(mp: MPState, perms: jnp.ndarray, clock: SlopeClock,
                 lam, steps)
             state = state._replace(inner=inner, cache=cache, avg=avg)
         else:
-            state = approx_pass(None, state, perm, lam)
+            state = approx_pass(None, state, perm, lam,
+                                occupancy=occupancy)
         return state, dual_value(state.inner.phi, lam)
 
     mp, t, stats = slope_batched_loop(

@@ -18,6 +18,7 @@ import jax
 # cycle (lint rule R001 points every other -1e30 spelling at this name).
 INVALID_SCORE = -1e30
 
+from . import approx_steps as _apx      # noqa: E402
 from . import flash_attention as _fa    # noqa: E402
 from . import moe_ffn as _moe           # noqa: E402
 from . import gram as _gram             # noqa: E402
@@ -74,6 +75,33 @@ def plane_select(planes, w, offsets, valid, *, neg=INVALID_SCORE, **kw):
     if use_pallas():
         return _psel.plane_select(planes, w, offsets, valid, neg=neg, **kw)
     return ref.plane_select_ref(planes, w, offsets, valid, neg)
+
+
+def approx_kernel_fits(planes, steps: int) -> bool:
+    """Whether an approximate pass of ``steps`` block steps over the plane
+    cache ``planes (n, cap, d+1)`` runs as the ``approx_block_steps``
+    kernel: on a TPU, with float32 planes, when the kernel's VMEM and
+    SMEM plan fits (:func:`repro.kernels.approx_steps.fits`)."""
+    n, cap, width = planes.shape
+    return (use_pallas() and planes.dtype == jax.numpy.float32
+            and _apx.fits(n, cap, width - 1, steps))
+
+
+def approx_occupancy(valid):
+    """``(hi, bits)`` of a ``(n, cap)`` valid mask, the per-block
+    occupied bound and slot bit mask :func:`approx_block_steps` reads;
+    they hold while no plane is inserted or evicted."""
+    return _apx.occupied_bound(valid), _apx.valid_bits(valid)
+
+
+def approx_block_steps(planes, valid, perm, phi, phi_i, bar, k0, gap, *,
+                       lam, occupancy):
+    """One approximate pass's block steps as one Pallas launch (TPU only;
+    callers check :func:`approx_kernel_fits` and run the scan elsewhere).
+    Returns ``(phi, phi_i, bar, gap, slots)``."""
+    hi, bits = occupancy
+    return _apx.approx_block_steps(planes, valid, perm, phi, phi_i, bar, k0,
+                                   gap, lam=lam, hi=hi, bits=bits)
 
 
 def viterbi_step(m, trans, **kw):
